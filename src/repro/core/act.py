@@ -52,6 +52,13 @@ __all__ = [
 # no context either, the policy defaults to FP32. The resolved site is
 # recorded on the context (residual shape/bits) for traced memory
 # accounting.
+#
+# Each op's body runs under ``jax.named_scope(<site>)`` (the site name it
+# qualifies, without the ``#k`` suffix), and the residual's quantize and
+# dequantize under ``act_quant`` / ``act_dequant``: the compiler keeps
+# these in every instruction's ``op_name``, which is how device time is
+# put down to op sites (DESIGN.md §13). The name stack feeds no scope
+# path, policy or key.
 # ---------------------------------------------------------------------------
 
 
@@ -98,20 +105,23 @@ def _record(ctx, name, op_kind, shape, policy: ACTPolicy) -> None:
 
 def _maybe_quantize(x: jax.Array, key: jax.Array, policy: ACTPolicy):
     """QTensor under an active policy, raw tensor otherwise (FP32 baseline)."""
-    if policy.active:
+    if not policy.active:
+        return x
+    with jax.named_scope("act_quant"):
         if policy.kernel == "pallas":
             from repro.kernels import ops as kops
 
             return kops.quantize(x, key, bits=policy.bits,
                                  stochastic=policy.stochastic)
-        return quantize(x, key, bits=policy.bits, stochastic=policy.stochastic)
-    return x
+        return quantize(x, key, bits=policy.bits,
+                        stochastic=policy.stochastic)
 
 
 def _maybe_dequantize(q) -> jax.Array:
-    if isinstance(q, QTensor):
+    if not isinstance(q, QTensor):
+        return q
+    with jax.named_scope("act_dequant"):
         return dequantize(q)
-    return q
 
 
 # ---------------------------------------------------------------------------
@@ -154,9 +164,10 @@ def act_matmul(x, w, *, key=None, policy: ACTPolicy | None = None,
     """
     name, policy, key, ctx = _resolve_site("matmul", scope, key, policy)
     _record(ctx, name, "matmul", x.shape, policy)
-    if not policy.enabled:
-        return jnp.einsum("...k,kn->...n", x, w)
-    return _act_matmul(policy, x, w, key)
+    with jax.named_scope(scope or "matmul"):
+        if not policy.enabled:
+            return jnp.einsum("...k,kn->...n", x, w)
+        return _act_matmul(policy, x, w, key)
 
 
 def act_dense(x, w, b, *, key=None, policy: ACTPolicy | None = None,
@@ -200,7 +211,8 @@ def act_relu(x, *, scope: str | None = None):
     if ctx is not None:
         ctx.record(ctx.qualify(scope or "relu"), "relu", x.shape, 1,
                    exact_mask=True)
-    return _act_relu(x)
+    with jax.named_scope(scope or "relu"):
+        return _act_relu(x)
 
 
 def _d_silu(x):
@@ -254,9 +266,10 @@ def act_nonlin(x, *, fn: str, key=None, policy: ACTPolicy | None = None,
     """Elementwise nonlinearity saving a quantized copy of its input."""
     name, policy, key, ctx = _resolve_site("nonlin", scope or fn, key, policy)
     _record(ctx, name, "nonlin", x.shape, policy)
-    if not policy.enabled:
-        return _NONLIN[fn][0](x)
-    return _act_nonlin(fn, policy, x, key)
+    with jax.named_scope(scope or fn):
+        if not policy.enabled:
+            return _NONLIN[fn][0](x)
+        return _act_nonlin(fn, policy, x, key)
 
 
 # ---------------------------------------------------------------------------
@@ -296,10 +309,11 @@ def act_rmsnorm(x, gamma, *, key=None, policy: ACTPolicy | None = None,
     """RMSNorm storing its input quantized; rstd recomputed from x̂ in bwd."""
     name, policy, key, ctx = _resolve_site("rmsnorm", scope, key, policy)
     _record(ctx, name, "rmsnorm", x.shape, policy)
-    if not policy.enabled:
-        r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-        return x * r * gamma
-    return _act_rmsnorm(policy, x, gamma, key, eps)
+    with jax.named_scope(scope or "rmsnorm"):
+        if not policy.enabled:
+            r = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+            return x * r * gamma
+        return _act_rmsnorm(policy, x, gamma, key, eps)
 
 
 # ---------------------------------------------------------------------------
@@ -438,20 +452,22 @@ def act_spmm(x, src, dst, ew, *, num_nodes: int, key=None,
                                            need_key=ew is not None)
     fused = policy.kernel == "pallas" and \
         _pallas_layout_ok(layout, x, src, num_nodes)
-    if ew is None:
+    if ew is not None:
+        _record(ctx, name, "spmm", x.shape, policy)
+    with jax.named_scope(scope or "spmm"):
+        if ew is None:
+            if fused:
+                leaves, treedef = jax.tree_util.tree_flatten(layout)
+                return _spmm_linear_pallas(treedef, x, *leaves)
+            msgs = x[src]
+            return jax.ops.segment_sum(msgs, dst, num_segments=num_nodes)
+        if not policy.enabled:
+            msgs = x[src] * ew[:, None]
+            return jax.ops.segment_sum(msgs, dst, num_segments=num_nodes)
         if fused:
             leaves, treedef = jax.tree_util.tree_flatten(layout)
-            return _spmm_linear_pallas(treedef, x, *leaves)
-        msgs = x[src]
-        return jax.ops.segment_sum(msgs, dst, num_segments=num_nodes)
-    _record(ctx, name, "spmm", x.shape, policy)
-    if not policy.enabled:
-        msgs = x[src] * ew[:, None]
-        return jax.ops.segment_sum(msgs, dst, num_segments=num_nodes)
-    if fused:
-        leaves, treedef = jax.tree_util.tree_flatten(layout)
-        return _act_spmm_pallas(policy, treedef, x, ew, key, *leaves)
-    return _act_spmm(policy, num_nodes, x, src, dst, ew, key)
+            return _act_spmm_pallas(policy, treedef, x, ew, key, *leaves)
+        return _act_spmm(policy, num_nodes, x, src, dst, ew, key)
 
 
 # ---------------------------------------------------------------------------
@@ -514,11 +530,12 @@ def act_remat(fn: Callable, policy: ACTPolicy | None = None, *,
         for i in range(1, repeat):  # scan buffers `repeat` instances
             _record(ctx, None if name is None else f"{name}[{i}]",
                     "remat", x.shape, pol)
-        if not pol.active:
-            if baseline is None:
-                baseline = jax.checkpoint(
-                    lambda params, x, consts: fn(params, x, consts))
-            return baseline(params, x, consts)
-        return active_path(pol)(params, x, key, consts)
+        with jax.named_scope(scope or "remat"):
+            if not pol.active:
+                if baseline is None:
+                    baseline = jax.checkpoint(
+                        lambda params, x, consts: fn(params, x, consts))
+                return baseline(params, x, consts)
+            return active_path(pol)(params, x, key, consts)
 
     return apply

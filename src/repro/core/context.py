@@ -5,7 +5,10 @@ itself) that gives every compressed op three things when the explicit
 ``key=`` / ``policy=`` kwargs are omitted:
 
   * a **named scope** — a ``/``-joined path like ``"kgat/layer2/spmm"``
-    built from ``ctx.scope(...)`` blocks plus the op's site name;
+    built from ``ctx.scope(...)`` blocks plus the op's site name. Each
+    component is also a ``jax.named_scope``, so the compiled ops carry
+    the site in their ``op_name`` metadata (device time per op site);
+    the name stack is write-only here, so no path or key depends on it;
   * a **policy** — resolved from the context's ``PolicySchedule`` by
     ``(op_kind, scope, layer)``, first matching rule wins;
   * a **stochastic-rounding key** — ``fold_in(fold_in(root, crc32(scope)),
@@ -95,10 +98,13 @@ class ActContext:
 
     @contextlib.contextmanager
     def scope(self, name: str) -> Iterator["ActContext"]:
-        """Push a scope path component for the ops traced inside."""
+        """Push a scope path component for the ops traced inside; it is
+        also a ``jax.named_scope``, so compiled ops carry it in their
+        ``op_name`` (the name stack never feeds a scope path or key)."""
         self._stack.append(name)
         try:
-            yield self
+            with jax.named_scope(name):
+                yield self
         finally:
             self._stack.pop()
 

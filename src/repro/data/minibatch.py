@@ -102,6 +102,9 @@ def _one_hop(adj: KGAdjacency, frontier: np.ndarray, fanout: int,
     offs = np.where(exact, np.minimum(ar, np.maximum(deg - 1, 0)[:, None]),
                     drawn)
     mask = np.where(exact, ar < deg[:, None], True)
+    if len(adj.src) == 0:   # an edgeless graph: every slot is a pad
+        nbr = np.repeat(frontier[:, None], fanout, axis=1)
+        return nbr, np.zeros(nbr.shape, adj.rel.dtype), mask
     e_ix = np.minimum(adj.indptr[frontier][:, None] + offs,
                       len(adj.src) - 1)
     nbr = adj.src[e_ix]

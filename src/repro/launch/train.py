@@ -62,7 +62,9 @@ import jax
 
 from repro.configs import ARCHS, get
 from repro.core.policy import schedule_from_cli, schedule_label
-from repro.obs import StepLogWriter, get_tracer, log, write_summary
+from repro.obs import (StepLogWriter, get_registry, get_tracer, log, span,
+                       write_summary)
+from repro.obs.sites import keep_compiled
 from repro.training.optimizer import adam
 from repro.training.step import make_train_step, step_metadata
 from repro.training.trainer import Trainer, TrainerConfig
@@ -265,7 +267,8 @@ def train(step, *, steps: int, schedule, schedule_spec: str,
                else None)
     placement = (step.dp_spec.placement_str() if n_model is not None
                  else None)
-    params = step.init(jax.random.PRNGKey(0))
+    with span("setup/init"):
+        params = step.init(jax.random.PRNGKey(0))
     if reshard_from:
         from repro.training import data_parallel as dp
         from repro.training.checkpoint import restore_checkpoint
@@ -306,9 +309,12 @@ def train(step, *, steps: int, schedule, schedule_spec: str,
     compile_s = None
     if mesh_spec is None:
         # compile ahead of the loop, so the first timed step is a run
-        t0 = time.perf_counter()
-        train_step = train_step.lower(state, first, 0).compile()
-        compile_s = time.perf_counter() - t0
+        with span("setup/compile"):
+            t0 = time.perf_counter()
+            train_step = train_step.lower(state, first, 0).compile()
+            compile_s = time.perf_counter() - t0
+        get_registry().gauge("setup/compile_s").set(compile_s)
+        keep_compiled("train/step", train_step)
         mem = train_step.memory_analysis()
         log(f"[train] step compiled in {compile_s:.2f}s; temp "
             f"{getattr(mem, 'temp_size_in_bytes', 'n/a')} B, arguments "
@@ -346,8 +352,9 @@ def train(step, *, steps: int, schedule, schedule_spec: str,
                       ckpt_meta=step_metadata(step, schedule_spec,
                                               mesh_spec=mesh_spec,
                                               placement=placement),
-                      step_writer=step_writer, items_per_step=n_edges
-                      ).restore_if_available()
+                      step_writer=step_writer, items_per_step=n_edges)
+    with span("setup/restore"):
+        trainer.restore_if_available()
     try:
         trainer.run()
     finally:

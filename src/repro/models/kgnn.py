@@ -21,6 +21,9 @@ memory analysis.
 Every op site carries a named scope (``"kgat/layer2/spmm"``): the ambient
 ``ActContext`` resolves its per-site policy from a ``PolicySchedule`` and
 derives its stochastic-rounding key from the scope hash (DESIGN.md §6).
+The same names, plus ``attention`` (kgat's edge weights), ``spmm`` (kgin's
+aggregation) and ``bpr`` (the head), are ``jax.named_scope``s, so compiled
+ops carry their site in ``op_name`` (DESIGN.md §13).
 
 **One step definition per arch** (DESIGN.md §9): every model's layer math
 is written ONCE against a ``GraphView`` — ``FullGraphView`` for the
@@ -572,15 +575,17 @@ def _kgat_attention(p, e: jax.Array, view) -> jax.Array:
     reads the all-gathered + halo-shrunk table, pad edges are masked out
     of the softmax normalization.
     """
-    proj = _basis_project(e, p["att_basis"])              # (B, rows, d)
-    proj_t = view.table(proj, axis=1)                     # (B, H, d)
-    coef = p["att_coef"][view.rel]                        # (E, B)
-    eh = _basis_mix(coef, proj_t[:, view.src])
-    et = _basis_mix(coef, proj[:, view.dst])
-    logits = jnp.sum(et * jnp.tanh(eh + p["relation"][view.rel]), axis=-1)
-    logits = view.mask_logits(logits)
-    return view.mask_weights(
-        segment_softmax(logits, view.dst, view.num_rows))
+    with jax.named_scope("attention"):
+        proj = _basis_project(e, p["att_basis"])          # (B, rows, d)
+        proj_t = view.table(proj, axis=1)                 # (B, H, d)
+        coef = p["att_coef"][view.rel]                    # (E, B)
+        eh = _basis_mix(coef, proj_t[:, view.src])
+        et = _basis_mix(coef, proj[:, view.dst])
+        logits = jnp.sum(et * jnp.tanh(eh + p["relation"][view.rel]),
+                         axis=-1)
+        logits = view.mask_logits(logits)
+        return view.mask_weights(
+            segment_softmax(logits, view.dst, view.num_rows))
 
 
 def _kgat_layer(p, layer: int, e: jax.Array, view, att: jax.Array, *,
@@ -624,13 +629,15 @@ def _kgin_layer(p, e: jax.Array, r_emb: jax.Array, view, *,
     # modulate by relation embedding per edge: gather-then-scale is O(E d);
     # act_spmm with per-edge weights handles the scalar part, the vector
     # modulation composes as two spmm passes over (e ⊙ e_r)-projected feats.
-    gathered = view.table(e)[view.src] * r_emb[view.rel]      # (E, d)
-    gathered = view.mask_messages(gathered)
-    deg = jax.ops.segment_sum(view.edge_ones(e.dtype), view.dst,
-                              num_segments=view.num_rows)
-    agg = jax.ops.segment_sum(gathered, view.dst,
-                              num_segments=view.num_rows)
-    agg = agg / jnp.maximum(deg, 1.0)[:, None]
+    # The aggregation is the "spmm" site, as act_spmm is for the others.
+    with jax.named_scope("spmm"):
+        gathered = view.table(e)[view.src] * r_emb[view.rel]  # (E, d)
+        gathered = view.mask_messages(gathered)
+        deg = jax.ops.segment_sum(view.edge_ones(e.dtype), view.dst,
+                                  num_segments=view.num_rows)
+        agg = jax.ops.segment_sum(gathered, view.dst,
+                                  num_segments=view.num_rows)
+        agg = agg / jnp.maximum(deg, 1.0)[:, None]
     return act_nonlin(agg, fn="leaky_relu", scope="act",
                       key=k.get("act"), policy=po.get("act"))
 
@@ -861,11 +868,12 @@ def bpr_loss(params: dict, g: CKG, batch: dict, cfg: KGNNConfig, *,
              key: jax.Array | None = None):
     """BPR pairwise ranking loss + L2 (the KGAT/KGIN objective)."""
     reps = propagate(params, g, cfg, policy=policy, key=key)
-    pos = score_pairs(reps, batch["user"], batch["pos"], cfg.n_users)
-    neg = score_pairs(reps, batch["user"], batch["neg"], cfg.n_users)
-    loss = -jnp.mean(jax.nn.log_sigmoid(pos - neg))
-    reg = sum(jnp.sum(x ** 2) for x in jax.tree_util.tree_leaves(params))
-    return loss + cfg.l2 * reg
+    with jax.named_scope("bpr"):
+        pos = score_pairs(reps, batch["user"], batch["pos"], cfg.n_users)
+        neg = score_pairs(reps, batch["user"], batch["neg"], cfg.n_users)
+        loss = -jnp.mean(jax.nn.log_sigmoid(pos - neg))
+        reg = sum(jnp.sum(x ** 2) for x in jax.tree_util.tree_leaves(params))
+        return loss + cfg.l2 * reg
 
 
 def sampled_reps(params: dict, view: "SampledGraphView", cfg: KGNNConfig, *,
@@ -900,11 +908,12 @@ def sampled_bpr_loss(params: dict, view: "SampledGraphView", cfg: KGNNConfig,
     """
     reps = sampled_reps(params, view, cfg, policy=policy, key=key)
     b = view.n_seeds // 3
-    pos = jnp.sum(reps[:b] * reps[b:2 * b], axis=-1)
-    neg = jnp.sum(reps[:b] * reps[2 * b:3 * b], axis=-1)
-    loss = -jnp.mean(jax.nn.log_sigmoid(pos - neg))
-    reg = sum(jnp.sum(x ** 2) for x in jax.tree_util.tree_leaves(params))
-    return loss + cfg.l2 * reg
+    with jax.named_scope("bpr"):
+        pos = jnp.sum(reps[:b] * reps[b:2 * b], axis=-1)
+        neg = jnp.sum(reps[:b] * reps[2 * b:3 * b], axis=-1)
+        loss = -jnp.mean(jax.nn.log_sigmoid(pos - neg))
+        reg = sum(jnp.sum(x ** 2) for x in jax.tree_util.tree_leaves(params))
+        return loss + cfg.l2 * reg
 
 
 def kg_shard_loss(params: dict, view, batch: dict, cfg: KGNNConfig, *,

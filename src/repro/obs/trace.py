@@ -1,41 +1,39 @@
 """Host-side span tracer with Chrome-trace / Perfetto JSON export.
 
 The tracer answers "where did the step's wall-clock go" at the *host*
-level — data fetch, device dispatch, host gather/scatter in the tiered
-store, serving batch drains — the seams the device profiler cannot see.
-Spans are plain ``(name, ts, dur, tid)`` complete events ("ph": "X"),
-so the export loads directly in Perfetto / chrome://tracing and nests
-by timestamp containment per thread.
+level — data fetch, device dispatch, checkpoint copies, host
+gather/scatter in the tiered store, serving batch drains. Spans are
+plain ``(name, ts, dur, tid)`` complete events ("ph": "X"), so the
+export loads directly in Perfetto / chrome://tracing and nests by
+timestamp containment per thread.
 
 Design constraints (DESIGN.md §13):
 
   * **near-zero overhead when disabled** — ``span()`` on a disabled
     tracer is one attribute check plus returning a shared no-op context
-    manager (no allocation, no clock read). The <2% tracing-off budget
-    is asserted in tests/test_obs.py.
+    manager (no allocation, no clock read, no jax import). The <2%
+    tracing-off budget is asserted in tests/test_obs.py.
   * **thread-aware** — events carry ``tid`` (``threading.get_ident``),
-    so the prefetch producer, the serving worker and the main loop land
-    on separate tracks.
-  * **device bracket** — ``step_span`` additionally enters
-    ``jax.profiler.StepTraceAnnotation`` when available, so a
-    simultaneously captured device profile aligns its steps with the
-    host spans (a no-op when no device profiler is collecting).
+    so the prefetch producer, the checkpoint writer and the main loop
+    land on separate tracks.
+  * **on the device trace's clock** — an enabled span also enters
+    ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` for a span
+    given ``step=``), so a jax profile captured at the same time holds
+    every program span on its host plane, beside the device ops (a
+    TraceMe costs well under a microsecond when no profiler collects).
 
 Span names reuse the ACT scope grammar (``/``-joined path components,
-e.g. ``train/step/gather`` — see DESIGN.md §6, §13).
+e.g. ``train/step/data`` — see DESIGN.md §6, §13).
 """
 
 from __future__ import annotations
 
-import contextlib
-import functools
 import json
 import os
 import threading
 import time
 
-__all__ = ["Tracer", "get_tracer", "enable", "disable", "span", "traced",
-           "step_span", "save"]
+__all__ = ["Tracer", "get_tracer", "span"]
 
 
 class _NullSpan:
@@ -54,9 +52,10 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    """One live span: clock read on enter, event append on exit."""
+    """One live span: a profiler annotation and a clock read on enter,
+    event append on exit."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_mark")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None):
         self._tracer = tracer
@@ -64,11 +63,18 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        step = self._args.get("step") if self._args else None
+        self._mark = (TraceAnnotation(self._name) if step is None
+                      else StepTraceAnnotation(self._name, step_num=step))
+        self._mark.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        self._mark.__exit__(*exc)
         tr = self._tracer
         ev = {"name": self._name, "ph": "X", "cat": "host",
               "ts": (self._t0 - tr._epoch) * 1e6,
@@ -114,25 +120,12 @@ class Tracer:
 
     def span(self, name: str, **args):
         """Context manager timing the enclosed block as one complete
-        event. Returns a shared no-op when disabled."""
+        event, under a profiler annotation of the same name; ``step=``
+        makes it the profiler's ``StepTraceAnnotation`` for that step.
+        Returns a shared no-op when disabled."""
         if not self.enabled:
             return _NULL
         return _Span(self, name, args or None)
-
-    def step_span(self, name: str, step: int):
-        """A per-step span that also brackets the device profiler's
-        ``StepTraceAnnotation`` (aligns host and device timelines when a
-        jax profile is being captured simultaneously)."""
-        if not self.enabled:
-            return _NULL
-        try:
-            from jax.profiler import StepTraceAnnotation
-        except Exception:  # pragma: no cover - jax always has it today
-            return _Span(self, name, {"step": step})
-        stack = contextlib.ExitStack()
-        stack.enter_context(_Span(self, name, {"step": step}))
-        stack.enter_context(StepTraceAnnotation(name, step_num=step))
-        return stack
 
     # -- export -------------------------------------------------------------
 
@@ -164,42 +157,6 @@ def get_tracer() -> Tracer:
     return _TRACER
 
 
-def enable() -> Tracer:
-    return _TRACER.enable()
-
-
-def disable() -> Tracer:
-    return _TRACER.disable()
-
-
 def span(name: str, **args):
     return _TRACER.span(name, **args)
 
-
-def step_span(name: str, step: int):
-    return _TRACER.step_span(name, step)
-
-
-def save(path: str, *, run: dict | None = None) -> str:
-    return _TRACER.save(path, run=run)
-
-
-def traced(fn_or_name=None):
-    """Decorator form: ``@traced`` or ``@traced("serve/score")``.
-
-    Disabled tracing costs one bool check per call — safe on warm paths.
-    """
-    def deco(fn, label=None):
-        label = label or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _TRACER.enabled:
-                return fn(*a, **kw)
-            with _TRACER.span(label):
-                return fn(*a, **kw)
-        return wrapper
-
-    if callable(fn_or_name):
-        return deco(fn_or_name)
-    return lambda fn: deco(fn, fn_or_name)

@@ -22,6 +22,8 @@ import threading
 import jax
 import numpy as np
 
+from repro.obs import span
+
 __all__ = ["save_checkpoint", "restore_checkpoint", "latest_step",
            "check_meta", "CheckpointManager"]
 
@@ -164,16 +166,26 @@ class CheckpointManager:
         self._thread: threading.Thread | None = None
 
     def save(self, step: int, tree) -> None:
-        # materialize on host BEFORE handing to the thread so training can
-        # donate/overwrite device buffers immediately
-        host_tree = jax.tree_util.tree_map(lambda x: np.asarray(x), tree)
-        self.wait()
-        if self.asynchronous:
-            self._thread = threading.Thread(
-                target=save_checkpoint, args=(self.base, step, host_tree),
-                kwargs={"keep": self.keep, "meta": self.meta}, daemon=True)
-            self._thread.start()
-        else:
+        """Spans (DESIGN.md §13): ``train/ckpt`` around the whole call,
+        ``train/ckpt/fetch`` (device to host copy) and ``train/ckpt/wait``
+        (join of the previous writer) inside it, ``train/ckpt/write`` on
+        the writer's thread."""
+        with span("train/ckpt"):
+            # materialize on host BEFORE handing to the thread so training
+            # can donate/overwrite device buffers immediately
+            with span("train/ckpt/fetch"):
+                host_tree = jax.tree_util.tree_map(np.asarray, tree)
+            with span("train/ckpt/wait"):
+                self.wait()
+            if self.asynchronous:
+                self._thread = threading.Thread(
+                    target=self._write, args=(step, host_tree), daemon=True)
+                self._thread.start()
+            else:
+                self._write(step, host_tree)
+
+    def _write(self, step: int, host_tree) -> None:
+        with span("train/ckpt/write"):
             save_checkpoint(self.base, step, host_tree, keep=self.keep,
                             meta=self.meta)
 

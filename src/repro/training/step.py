@@ -177,7 +177,8 @@ def make_train_step(step: ModelStep, opt, *, schedule=None,
             return step.loss(p, batch, ctx=ctx)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
-        params, opt_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(grads, opt_state, params)
         return (params, opt_state), {"loss": loss}
 
     return train_step

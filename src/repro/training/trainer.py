@@ -23,7 +23,7 @@ from typing import Callable, Iterator
 
 import jax
 
-from repro.obs import get_registry, log as obs_log, step_span, span
+from repro.obs import get_registry, log as obs_log, span
 
 from .checkpoint import CheckpointManager
 
@@ -63,9 +63,12 @@ class PrefetchIterator:
         self._done = False
 
         def worker():
-            for item in it:
-                if not self._put(item):
-                    return
+            source = iter(it)
+            while True:
+                with span("train/data/sample"):
+                    item = next(source, StopIteration)
+                if item is StopIteration or not self._put(item):
+                    break
             self._put(StopIteration)
 
         self._thread = threading.Thread(target=worker, daemon=True)
@@ -169,7 +172,7 @@ class Trainer:
         cfg = self.cfg
         while self.step < cfg.total_steps:
             try:
-                with step_span("train/step", self.step):
+                with span("train/step", step=self.step):
                     with span("train/step/data"):
                         batch = self.data.next()
                     if self.failure_injector is not None and \
@@ -197,20 +200,8 @@ class Trainer:
                              f"{dt:.3f}s vs ema {self._step_ema:.3f}s")
                 self.step += 1
                 self._failures = 0
-                record = None
-                if self.step_writer is not None:
-                    record = {"step": self.step,
-                              "wall_ms": round(dt * 1e3, 4)}
-                if self.step % cfg.log_every == 0:
-                    m = {k: float(v) for k, v in metrics.items()}
-                    self.history.append({"step": self.step, **m})
-                    self.log(f"[trainer] step {self.step}: {m}")
-                    if "loss" in m:
-                        self._m_loss.set(m["loss"])
-                    if record is not None:
-                        record.update(m)
-                if record is not None:
-                    self.step_writer.write(record)
+                with span("train/log"):
+                    self._log_step(dt, metrics)
                 if self.step % cfg.ckpt_every == 0:
                     self.ckpt.save(self.step, self.state)
             except StopIteration:
@@ -229,3 +220,20 @@ class Trainer:
         self.ckpt.save(self.step, self.state)
         self.ckpt.wait()
         return self.state
+
+    def _log_step(self, dt: float, metrics: dict) -> None:
+        """The log point (the metrics' host conversion, ``history``, the
+        progress line, the loss gauge) and the step writer's record."""
+        record = None
+        if self.step_writer is not None:
+            record = {"step": self.step, "wall_ms": round(dt * 1e3, 4)}
+        if self.step % self.cfg.log_every == 0:
+            m = {k: float(v) for k, v in metrics.items()}
+            self.history.append({"step": self.step, **m})
+            self.log(f"[trainer] step {self.step}: {m}")
+            if "loss" in m:
+                self._m_loss.set(m["loss"])
+            if record is not None:
+                record.update(m)
+        if record is not None:
+            self.step_writer.write(record)

@@ -132,3 +132,43 @@ def test_act_remat_grad_close_and_fp32_exact():
         g = jax.grad(lambda p: f(p, x, KEY).sum())(w)
         rel = float(jnp.abs(g - exact).max() / jnp.abs(exact).max())
         assert rel < tol, (pol.bits, rel)
+
+
+# -- op sites on the compiled step (DESIGN.md §13) ---------------------------
+
+
+@pytest.mark.parametrize("arch", ["kgat", "kgin"])
+def test_compiled_step_ops_carry_their_site(arch):
+    """Every gather, scatter and dot of a tiny INT2 train step names the
+    model, the BPR head or the optimizer in its ``op_name``, and the ACT
+    quantize/dequantize, the aggregation and (kgat) the attention sites
+    all appear."""
+    import re
+
+    from repro.configs import get
+    from repro.core.policy import schedule_from_cli
+    from repro.models.registry import build_step
+    from repro.obs.sites import scopes
+    from repro.training.optimizer import adam
+    from repro.training.step import make_train_step
+
+    sched = schedule_from_cli(None, 2)
+    step = build_step(get(arch), schedule=sched, dim=8, n_layers=2)
+    opt = adam(1e-3)
+    params = step.init(jax.random.PRNGKey(0))
+    text = make_train_step(step, opt, schedule=sched, root_key=KEY).lower(
+        (params, opt.init(params)), next(step.batches()), 0
+    ).compile().as_text()
+    seen, checked = set(), 0
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m:
+            seen |= set(scopes(m.group(1)))
+        if re.search(r"= \S+ (gather|scatter|dot)\(", line):
+            assert m and set(scopes(m.group(1))) & {arch, "bpr",
+                                                    "optimizer"}, line
+            checked += 1
+    assert checked > 10
+    want = {"act_quant", "act_dequant", "spmm", "bpr", "optimizer"}
+    assert want | ({"attention"} if arch == "kgat" else set()) <= seen
+

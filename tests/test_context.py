@@ -101,6 +101,44 @@ def test_mixed_schedule_per_site_bits_in_trace():
     assert rest and set(rest.values()) == {2}
 
 
+# --- jax named scopes: metadata only ---------------------------------------
+
+
+def test_named_scopes_move_no_scope_path_and_no_bit(monkeypatch):
+    """The op-site ``jax.named_scope``s only label compiled ops: a tiny
+    kgat INT2 step records the same scope paths, and its loss and
+    gradients are bit-identical to a build with every named scope off."""
+    import contextlib
+
+    _, cfg, g, params, batch = _tiny_kg(dim=8, n_layers=2)
+    root = jax.random.PRNGKey(7)
+
+    def build():
+        ctx = ActContext(INT2, root, step=3)
+
+        def loss(p):
+            with ctx:
+                return kgnn.bpr_loss(p, g, batch, cfg)
+
+        return ctx, jax.jit(jax.value_and_grad(loss))(params)
+
+    ctx, (loss, grads) = build()
+    assert [(r.scope, r.op_kind) for r in ctx.records] == [
+        ("kgat/layer0/spmm", "spmm"), ("kgat/layer0/w1", "matmul"),
+        ("kgat/layer0/w2", "matmul"), ("kgat/layer0/act1", "nonlin"),
+        ("kgat/layer0/act2", "nonlin"), ("kgat/layer1/spmm", "spmm"),
+        ("kgat/layer1/w1", "matmul"), ("kgat/layer1/w2", "matmul"),
+        ("kgat/layer1/act1", "nonlin"), ("kgat/layer1/act2", "nonlin")]
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare, (loss0, grads0) = build()
+    assert bare.records == ctx.records
+    assert np.asarray(loss).tobytes() == np.asarray(loss0).tobytes()
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(grads0)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 # --- explicit kwargs vs context: bit-identical grads -----------------------
 
 

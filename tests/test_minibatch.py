@@ -38,7 +38,7 @@ from repro.models.kgnn import (  # noqa: E402
     sampled_reps)
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:      # container image has no hypothesis; the seeded
@@ -136,6 +136,7 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=30, deadline=None)
     @given(st.integers(0, 2 ** 31 - 1), st.integers(1, 3),
            st.integers(1, 6))
+    @example(29424472, 1, 1)      # an edgeless graph (19 nodes, 0 edges)
     def test_sampler_in_range_property(seed, n_hops, fanout):
         """Property: arbitrary graph/seed draws never produce an
         out-of-range block index (hypothesis build of the sweep)."""

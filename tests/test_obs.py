@@ -4,7 +4,9 @@ and the instrumented seams.
 Covers, in rough dependency order:
 
   * ``repro.obs.trace`` — span nesting/threading in the Chrome-trace
-    export, disabled fast path, the ``@traced`` decorator, save();
+    export, disabled fast path, the profiler annotation each enabled
+    span enters, save(); the trainer's, checkpoint writer's and
+    sampler's spans on their own threads;
   * ``repro.obs.metrics`` — bounded reservoir (memory + exactness +
     determinism), labeled series, snapshot/diff;
   * ``repro.obs.sinks`` — summary round-trip, NAMED schema violations,
@@ -104,39 +106,138 @@ def test_tracer_chrome_trace_shape_and_save(tmp_path):
     assert loaded["traceEvents"][0]["name"] == "x"
 
 
-def test_traced_decorator_both_forms():
-    tr = obs.get_tracer()
+class _Marks:
+    """Stands in jax.profiler's annotations, recording what is entered."""
+
+    def __init__(self):
+        self.entered = []
+
+    def __call__(self, kind):
+        marks = self
+
+        class Mark:
+            def __init__(self, name, **kw):
+                marks.entered.append((kind, name, kw.get("step_num")))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        return Mark
+
+
+@pytest.fixture
+def marks(monkeypatch):
+    import jax.profiler
+
+    m = _Marks()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", m("trace"))
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", m("step"))
+    return m
+
+
+def test_enabled_span_enters_a_trace_annotation(marks):
+    tr = obs.Tracer()
+    with tr.span("train/step/data"):
+        pass
+    assert marks.entered == []                 # disabled: no annotation
     tr.enable()
-    try:
-        @obs.traced
-        def f(x):
-            return x + 1
-
-        @obs.traced("custom/label")
-        def g(x):
-            return x * 2
-
-        assert f(1) == 2 and g(2) == 4
-        names = [e["name"] for e in tr.events()]
-        assert "custom/label" in names
-        assert any("f" in n for n in names)
-    finally:
-        tr.disable()
-
-
-def test_step_span_enters_jax_annotation():
-    # StepTraceAnnotation is a no-op without an active profiler, but the
-    # ExitStack path must still record the host span
-    tr = obs.get_tracer()
-    tr.enable()
-    try:
-        with obs.step_span("train/step", 3):
+    with tr.span("train/ckpt"):
+        with tr.span("train/ckpt/fetch"):
             pass
-        evs = tr.events()
-        assert evs and evs[-1]["name"] == "train/step"
-        assert evs[-1]["args"] == {"step": 3}
+    assert marks.entered == [("trace", "train/ckpt", None),
+                             ("trace", "train/ckpt/fetch", None)]
+    assert [e["name"] for e in tr.events()] == ["train/ckpt/fetch",
+                                                 "train/ckpt"]
+
+
+def test_step_span_enters_jax_annotation(marks):
+    # a span given step= is the profiler's StepTraceAnnotation for that
+    # step, and still records the host span with its step
+    tr = obs.Tracer().enable()
+    with tr.span("train/step", step=3):
+        pass
+    assert marks.entered == [("step", "train/step", 3)]
+    evs = tr.events()
+    assert evs and evs[-1]["name"] == "train/step"
+    assert evs[-1]["args"] == {"step": 3}
+
+
+def test_trainer_spans_land_on_their_threads(tmp_path):
+    """A toy run checkpointing every 2 steps: the checkpoint's fetch and
+    join and the log point on the main thread, the write on the writer's
+    thread, one sample span per batch on the prefetch producer's."""
+    from repro.training.trainer import Trainer, TrainerConfig
+
+    cfg = TrainerConfig(total_steps=4, ckpt_dir=str(tmp_path), ckpt_every=2,
+                        log_every=2)
+    tracer = obs.get_tracer().enable()
+    try:
+        tr = Trainer(lambda s, b, i: (s + b, {"loss": np.float32(i)}),
+                     np.zeros(2, np.float32),
+                     iter([np.ones(2, np.float32)] * 4), cfg,
+                     log_fn=lambda msg: None)
+        tr.run()
     finally:
-        tr.disable()
+        tracer.disable()
+        evs = tracer.events()
+        tracer.enable().disable()           # leave no events behind
+    main = threading.get_ident()
+    tids = {}
+    for e in evs:
+        tids.setdefault(e["name"], set()).add(e["tid"])
+    for name in ("train/step", "train/step/data", "train/step/update",
+                 "train/log", "train/ckpt", "train/ckpt/fetch",
+                 "train/ckpt/wait"):
+        assert tids[name] == {main}, name
+    # (a thread's ident may be reused once it has exited)
+    assert main not in tids["train/ckpt/write"] | tids["train/data/sample"]
+    names = [e["name"] for e in evs]
+    assert names.count("train/ckpt") == 3       # steps 2 and 4, and the end
+    assert names.count("train/ckpt/write") == 3
+    assert names.count("train/data/sample") == 5    # 4 batches + the end
+    assert names.count("train/log") == 4
+
+
+def test_op_sites_of_a_kept_program(monkeypatch):
+    """An instruction's sites are its ``op_name``'s named scopes, wrappers
+    stripped, the outer and inner jitted functions and the primitive left
+    out; the table is rendered from the kept program only when asked."""
+    from repro.obs import sites
+
+    assert sites.scopes("jit(train_step)/transpose(jvp(kgat))/layer0/spmm/"
+                        "act_dequant/jit(dequantize)/mul") == (
+        "kgat", "layer0", "spmm", "act_dequant")
+    assert sites.scopes("jit(train_step)/jit(_threefry_fold_in)/add") == ()
+    assert sites.scopes("") == ()
+    text = (
+        'ENTRY %main.9 (p.1: f32[8]) -> f32[8] {\n'
+        '  %p.1 = f32[8]{0} parameter(0), metadata={op_name="state[0]"}\n'
+        '  %fusion.3 = (f32[4,8]{1,0}, s32[]) fusion(%p.1), kind=kLoop, '
+        'metadata={op_name="jit(train_step)/jvp(kgat)/attention/add" '
+        'source_line=3}\n'
+        '  %copy.1 = f32[8]{0} copy(%p.1)\n'
+        '  ROOT %scatter.2 = f32[8]{0} scatter(%copy.1), '
+        'metadata={op_name="jit(train_step)/optimizer/sub"}\n}\n')
+    want = {"p.1": ("f32[8]", ()),
+            "fusion.3": ("f32[4,8]", ("kgat", "attention")),
+            "scatter.2": ("f32[8]", ("optimizer",))}
+    assert sites.parse(text) == want
+
+    rendered = []
+
+    class Compiled:
+        def as_text(self):
+            rendered.append(1)
+            return text
+
+    monkeypatch.setattr(sites, "_KEPT", {})
+    assert sites.op_sites("train/step") is None
+    sites.keep_compiled("train/step", Compiled())
+    assert rendered == []
+    assert sites.op_sites("train/step") == want and rendered == [1]
 
 
 # ---------------------------------------------------------------------------
@@ -559,9 +660,10 @@ def test_publish_activation_report_gauges():
 
 def test_disabled_instrumentation_under_two_percent():
     """DESIGN.md §13 budget: with tracing disabled, the per-step cost of
-    the instrumentation bundle (4 span checks + histogram observe +
-    counter inc — what Trainer._run and the sampled loop add per step)
-    must stay under 2% of the smoke-config median step time. Measured
+    the instrumentation bundle (the spans Trainer._run, the checkpoint
+    manager and the prefetch producer enter per step, the sampled loop's
+    gather span, histogram observe + counter inc) must stay under 2% of
+    the smoke-config median step time. Measured
     directly instead of diffing two noisy end-to-end runs: CPU step time
     is ~ms, the bundle is ~µs, so the assertion has two orders of
     headroom and stays deterministic."""
@@ -574,11 +676,22 @@ def test_disabled_instrumentation_under_two_percent():
     n = 20_000
     t0 = time.perf_counter()
     for i in range(n):
-        with tr.span("train/step"):
+        with tr.span("train/data/sample"):
+            pass
+        with tr.span("train/step", step=i):
             with tr.span("train/step/data"):
                 pass
             with tr.span("train/step/update"):
                 pass
+        with tr.span("train/log"):
+            pass
+        with tr.span("train/ckpt"):
+            with tr.span("train/ckpt/fetch"):
+                pass
+            with tr.span("train/ckpt/wait"):
+                pass
+        with tr.span("train/ckpt/write"):
+            pass
         with tr.span("train/step/gather"):
             pass
         ctr.inc()
@@ -620,8 +733,10 @@ def test_launch_trace_and_metrics_end_to_end(tmp_path):
     doc = json.load(open(trace_path))
     evs = doc["traceEvents"]
     names = [e["name"] for e in evs]
-    assert {"train", "train/step", "train/step/data",
-            "train/step/update"} <= set(names)
+    assert {"train", "train/step", "train/step/data", "train/step/update",
+            "train/log", "train/ckpt", "train/ckpt/write",
+            "train/data/sample", "setup/init", "setup/compile",
+            "setup/restore"} <= set(names)
     assert names.count("train/step") == 5
     # nesting: every step span sits inside the train span's window
     train = next(e for e in evs if e["name"] == "train")
